@@ -10,8 +10,10 @@ timeline (client-update dispatch + staleness-weighted buffer merge).  The
 sequential baseline (``run_round_reference``) is the pre-fusion engine: one
 jit dispatch plus a blocking ``float()`` sync per client.
 
-Measurements run in a subprocess so the client mesh can be backed by forced
-host-platform devices (``XLA_FLAGS`` must be set before jax initialises).
+On the CPU, measurements run in a subprocess so the client mesh can be
+backed by forced host-platform devices (``XLA_FLAGS`` must be set before jax
+initialises); on a TPU they run in the process that holds the chips, over
+the devices ``jax.devices()`` lists (``benchmarks.common.measure``).
 Results go to ``BENCH_fedround.json``: the latest run at the top level, plus
 a ``history`` list (one entry per run, keyed by git SHA + timestamp) so the
 perf trajectory is tracked across PRs instead of overwritten.
@@ -61,7 +63,6 @@ swept over local_steps; decode at gen_len 17 (≥16).
 from __future__ import annotations
 
 import argparse
-import os
 import sys
 import time
 
@@ -682,6 +683,8 @@ def _mesh_measure() -> dict:
     out = {"devices": jax.device_count(), "timed_rounds": MESH_TIMED_ROUNDS,
            "shapes": {}}
     for nc, nm in MESH_SHAPES:
+        if nc * nm > jax.device_count():
+            continue
         mesh = None
         if nc * nm > 1:
             mesh = Mesh(np.array(jax.devices()[: nc * nm]).reshape(nc, nm),
@@ -780,8 +783,9 @@ def _append_history(res: dict, path: str = "BENCH_fedround.json") -> dict:
 
 
 def main(argv: list[str] | None = None) -> list[str]:
-    """Spawn the measurement subprocess (forced host devices for the client
-    mesh), append to BENCH_fedround.json's history, return CSV lines.
+    """Measure every section (see ``benchmarks.common.measure``: forced
+    host devices in a subprocess on the CPU, in-process on a TPU), append
+    to BENCH_fedround.json's history, return CSV lines.
     ``--quick``: dispatch-count check only, in-process, nothing written.
     ``argv=None`` (the ``benchmarks.run`` harness, which leaves the suite
     name in ``sys.argv``) means no flags — only ``__main__`` passes argv."""
@@ -817,37 +821,14 @@ def main(argv: list[str] | None = None) -> list[str]:
                 for mode, cc in sorted(counts.items())
                 for name, cnt in sorted(cc.items())]
 
-    n_sample = 4                    # round(0.4 * 10)
-    ndev = max(d for d in (1, 2, 4)
-               if d <= (os.cpu_count() or 1) and n_sample % d == 0)
-    from benchmarks.common import run_measurement_subprocess
-    env = dict(os.environ)
-    flags = env.get("XLA_FLAGS", "")
-    env["XLA_FLAGS"] = (flags + f" --xla_force_host_platform_device_count={ndev}").strip()
-    code = ("import json; from benchmarks.bench_fedround import _measure, _JSON_TAG; "
-            "print(_JSON_TAG + json.dumps(_measure()))")
-    res = run_measurement_subprocess(code, _JSON_TAG, env=env)
-    # mesh section: its own subprocess — the shapes need 4 forced devices
-    env_m = dict(os.environ)
-    env_m["XLA_FLAGS"] = (flags +
-                          " --xla_force_host_platform_device_count=4").strip()
-    code_m = ("import json; from benchmarks.bench_fedround import "
-              "_mesh_measure, _MESH_JSON_TAG; "
-              "print(_MESH_JSON_TAG + json.dumps(_mesh_measure()))")
-    res["mesh"] = run_measurement_subprocess(code_m, _MESH_JSON_TAG, env=env_m)
-    # population section: its own subprocess — single device, hosted K sweep
-    code_p = ("import json; from benchmarks.bench_fedround import "
-              "_population_measure, _POP_JSON_TAG; "
-              "print(_POP_JSON_TAG + json.dumps(_population_measure()))")
-    res["population"] = run_measurement_subprocess(code_p, _POP_JSON_TAG,
-                                                   env=dict(os.environ))
-    # robustness section: its own subprocess — single device, fault sweep
-    code_r = ("import json; from benchmarks.bench_fedround import "
-              "_robustness_measure, _ROBUST_JSON_TAG; "
-              "print(_ROBUST_JSON_TAG + json.dumps(_robustness_measure()))")
-    res["robustness"] = run_measurement_subprocess(code_r, _ROBUST_JSON_TAG,
-                                                   env=dict(os.environ),
-                                                   timeout=3600)
+    from benchmarks.common import measure
+    # forced host devices back the client mesh on the CPU; on a TPU every
+    # section measures in this process over the chips jax.devices() lists
+    res = measure(_measure, _JSON_TAG, host_devices=4)
+    res["mesh"] = measure(_mesh_measure, _MESH_JSON_TAG, host_devices=4)
+    res["population"] = measure(_population_measure, _POP_JSON_TAG)
+    res["robustness"] = measure(_robustness_measure, _ROBUST_JSON_TAG,
+                                timeout=3600)
     _append_history(res)
 
     lines = []

@@ -570,7 +570,8 @@ def quick_slo_check() -> dict:
 
 
 def main(argv: list[str] | None = None) -> list[str]:
-    """Spawn the measurement subprocess, append to BENCH_serving.json's
+    """Measure (in a subprocess on the CPU, in this process on a TPU — see
+    ``benchmarks.common.measure``), append to BENCH_serving.json's
     history, return CSV lines.  ``--quick``: dispatch-count check only,
     in-process, nothing written."""
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
@@ -618,10 +619,8 @@ def main(argv: list[str] | None = None) -> list[str]:
                              f"prefill,0.0,{rec['expected_serve_prefill']}")
         return lines
 
-    from benchmarks.common import append_history, run_measurement_subprocess
-    code = ("import json; from benchmarks.bench_serving import _measure, "
-            "_JSON_TAG; print(_JSON_TAG + json.dumps(_measure()))")
-    res = run_measurement_subprocess(code, _JSON_TAG)
+    from benchmarks.common import append_history, measure
+    res = measure(_measure, _JSON_TAG)
     append_history(res, "BENCH_serving.json")
 
     lines = []

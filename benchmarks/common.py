@@ -48,7 +48,13 @@ def build_trainer(dataset: str = "samllava", *, aggregator: str = "fedilora",
                   tcfg: SyntheticTaskConfig | None = None,
                   faults: FaultConfig | None = None,
                   clip_norm: float = 0.0,
-                  trim_frac: float = 0.0) -> FederatedTrainer:
+                  trim_frac: float = 0.0,
+                  model: str = "fedbench-tiny", base_params=None,
+                  mesh=None) -> FederatedTrainer:
+    """The paper protocol on the synthetic multimodal task (see the module
+    docstring) over the registered config ``model``; ``base_params`` shares
+    one frozen base between same-seed trainers, ``mesh`` is the round mesh
+    (``FederatedTrainer(mesh=...)``)."""
     tseed = DATASETS[dataset]
     tcfg = tcfg or SyntheticTaskConfig(seed=tseed)
     sizes = heterogeneous_sizes(NUM_CLIENTS, examples, seed=tseed)
@@ -71,8 +77,9 @@ def build_trainer(dataset: str = "samllava", *, aggregator: str = "fedilora",
         faults=faults or FaultConfig(), clip_norm=clip_norm,
         trim_frac=trim_frac)
     ocfg = OptimizerConfig(peak_lr=3e-3, total_steps=600)
-    return FederatedTrainer(get_config("fedbench-tiny"), fcfg, ocfg,
-                            ctrain, ceval, gtest, seed=seed)
+    return FederatedTrainer(get_config(model), fcfg, ocfg, ctrain, ceval,
+                            gtest, base_params=base_params, seed=seed,
+                            mesh=mesh)
 
 
 def run_rounds(trainer: FederatedTrainer, rounds: int = DEFAULT_ROUNDS):
@@ -86,12 +93,39 @@ def csv_line(name: str, us_per_call: float, derived) -> str:
     return f"{name},{us_per_call:.1f},{derived}"
 
 
+def measure(fn, tag: str, *, host_devices: int = 1,
+            timeout: int = 2400) -> dict:
+    """Run the module-level measurement function ``fn`` (returns a JSON-able
+    dict) — the protocol shared by bench_fedround and bench_serving.
+
+    On a TPU backend ``fn`` runs in THIS process: a chip belongs to the
+    process that opened it, so a child could not reach it.  On the CPU
+    backend it runs in a fresh interpreter with ``host_devices`` forced host
+    devices (the XLA flag must be set before JAX initialises there) and the
+    ``tag``-prefixed JSON line it prints is scraped.  Any other backend
+    raises."""
+    import jax
+
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return fn()
+    if backend != "cpu":
+        raise RuntimeError(f"benchmarks measure on a TPU (or the CPU test "
+                           f"backend), not on {backend!r}")
+    env = dict(os.environ)
+    if host_devices > 1:
+        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " --xla_force_host_"
+                            f"platform_device_count={host_devices}").strip()
+    code = (f"import json; from {fn.__module__} import {fn.__name__} as f; "
+            f"print({tag!r} + json.dumps(f()))")
+    return run_measurement_subprocess(code, tag, env=env, timeout=timeout)
+
+
 def run_measurement_subprocess(code: str, tag: str, *, env: dict | None = None,
                                timeout: int = 2400) -> dict:
     """Run ``code`` in a fresh python (clean jax init — XLA flags / device
     counts must be set before jax imports) and scrape the ``tag``-prefixed
-    JSON line it prints — the measurement protocol shared by bench_fedround
-    and bench_serving."""
+    JSON line it prints.  CPU only: :func:`measure` is the entry point."""
     env = dict(os.environ) if env is None else env
     env.setdefault("PYTHONPATH", os.path.join(os.path.dirname(__file__), ".."))
     proc = subprocess.run([sys.executable, "-c", code], capture_output=True,

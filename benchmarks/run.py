@@ -100,12 +100,19 @@ def trajectory(root: str | None = None) -> list[str]:
     return lines
 
 
-def main() -> None:
+def main() -> int:
+    """Run the named suites (all by default); returns the exit code — 1 if
+    any suite raised.  A failing suite is recorded as an ``ERROR`` row and
+    the remaining suites still run."""
     args = sys.argv[1:]
     if "--trajectory" in args:
         print("\n".join(trajectory()))
-        return
+        return 0
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     wanted = args or list(SUITES)
+    failed = []
     print("name,us_per_call,derived")
     for name in wanted:
         t0 = time.perf_counter()
@@ -113,10 +120,14 @@ def main() -> None:
             for line in SUITES[name]():
                 print(line, flush=True)
         except Exception as e:  # keep the harness going; record the failure
+            failed.append(name)
             print(f"{name}/ERROR,0.0,{type(e).__name__}: {e}", flush=True)
-        print(f"{name}/_suite_wall,{(time.perf_counter()-t0)*1e6:.0f},done",
-              flush=True)
+        print(f"{name}/_suite_wall,{(time.perf_counter()-t0)*1e6:.0f},"
+              f"{'failed' if name in failed else 'done'}", flush=True)
+    if failed:
+        print(f"failed suites: {', '.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

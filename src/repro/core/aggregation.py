@@ -330,9 +330,10 @@ def fedbuff_kernel(stacked: Pytree, ranks: jax.Array, p: jax.Array,
 def fedilora_kernel(stacked: Pytree, ranks: jax.Array, p: jax.Array,
                     fallback: Pytree | None = None) -> Pytree:
     """Pallas dimension-wise aggregation (repro/kernels/dim_agg.py) —
-    numerically identical to :func:`fedilora` (tested); on TPU the per-leaf
-    reduction lowers to a fused Mosaic kernel, on CPU it runs in interpret
-    mode.  Imported lazily to keep core free of a kernels dependency."""
+    numerically identical to :func:`fedilora` (tested); on a TPU backend
+    the per-leaf reduction always lowers to a fused Mosaic kernel, and only
+    the CPU test backend runs it in interpret mode (``kernels/ops.py``).
+    Imported lazily to keep core free of a kernels dependency."""
     from repro.kernels.ops import fedilora_aggregate_tree
 
     return _apply_fallback(fedilora_aggregate_tree(stacked, ranks, p), p,
@@ -524,8 +525,8 @@ def aggregate(name: str, stacked: Pytree, ranks: jax.Array, p: jax.Array, *,
     """Dispatch one server aggregation through :data:`AGGREGATORS`.
 
     Returns ``(global_lora, base_delta)``; see the registry comment above.
-    Pure and jit-able for every strategy (the kernel path runs Pallas in
-    interpret mode off-TPU).
+    Pure and jit-able for every strategy (the kernel entries lower to
+    Mosaic on a TPU backend and interpret only on the CPU backend).
     """
     try:
         fn = AGGREGATORS[name]

@@ -691,6 +691,9 @@ class FederatedTrainer:
         slots (``cids`` always carries the global ids — flora's fresh-init
         PRNG folds them, never slots)."""
         paged = self.fcfg.paged
+        # first: on a mesh this places the state read below, so round 1 sees
+        # the same operand shardings as every later round (one compile)
+        step = self._get_round_step()
         cids = jnp.asarray(sampled, jnp.int32)
         if paged:
             slots = self.store.acquire_cohort(sampled)
@@ -713,8 +716,7 @@ class FederatedTrainer:
             warnings.filterwarnings(
                 "ignore", message="Some donated buffers were not usable")
             out = self._dispatch(
-                "round_step", self._get_round_step(),
-                self.base_params, lora, self.server.global_lora,
+                "round_step", step, self.base_params, lora, self.server.global_lora,
                 self.server.prev_global, ranks, sizes, data, idx, cids,
                 jnp.asarray(batch_idx, jnp.int32),
                 jnp.asarray(self.server.round, jnp.int32), *fault_args)
@@ -920,6 +922,7 @@ class FederatedTrainer:
                                for k in ("keep", "weight", "scale", "nan")},)
             measure = fc.measure_delays and \
                 not self._ema_seen[list(map(int, sampled))].all()
+            step = self._get_client_update_step()   # places mesh state first
             if fc.paged:
                 # the cohort stays PINNED until it retires — its bank rows
                 # hold the post-update adapters the eviction write-back
@@ -936,8 +939,7 @@ class FederatedTrainer:
                     self._stacked_data)
             t0 = time.perf_counter()
             out = self._dispatch(
-                "client_update", self._get_client_update_step(),
-                self.base_params, lora_in, self.server.global_lora,
+                "client_update", step, self.base_params, lora_in, self.server.global_lora,
                 self.server.prev_global, ranks_in, sizes_in, data_in, idx,
                 jnp.asarray(batch_idx, jnp.int32), *fault_args)
             if measure:

@@ -23,11 +23,12 @@ of materialising a discounted [K, r_g] weight matrix in HBM first (ops.py's
 ``fedbuff_aggregate_tree`` / ``fedilora_clip_tree`` are the callers).
 
 ``dim_agg_trimmed_pallas`` is the Byzantine-robust sibling: per scalar
-element it computes each client's counting rank among the covering clients
-(a K×K comparison held entirely in VMEM), discards the ``t[d]`` smallest and
-largest contributions, and renormalises the surviving weights — the
-dimension-wise trimmed mean, one HBM pass, no [K, K, ...] materialisation
-outside the block.
+element it computes each client's counting rank among the covering clients,
+discards the ``t[d]`` smallest and largest contributions, and renormalises
+the surviving weights — the dimension-wise trimmed mean, one HBM pass.  The
+K×K comparison is unrolled over client pairs on 2-D ``[r, bn]`` tiles (the
+index tie-break is static per pair), so VMEM holds O(K·r·bn), never a
+[K, K, r, bn] block, and Mosaic sees no rank-5 broadcast.
 """
 
 from __future__ import annotations
@@ -54,33 +55,38 @@ def _kernel_scaled(x_ref, w_ref, s_ref, o_ref):
     o_ref[...] = acc.astype(o_ref.dtype)
 
 
-def _kernel_trimmed(x_ref, p_ref, c_ref, t_ref, o_ref):
+def _kernel_trimmed(x_ref, pw_ref, c_ref, t_ref, o_ref):
     """Per-element trimmed weighted mean over the client axis.
 
-    x [K, 1, r, bn]; p [K, 1] client weights; c [K, r] coverage (rank mask ×
-    participation); t [1, r] per-dimension trim counts.  For every scalar
-    element, client k's counting rank among covering clients is computed by
-    comparing against all K values (ties broken by client index, so the
-    trim set is deterministic under duplicates); contributions ranked inside
-    either ``t[d]``-tail are dropped and the survivors renormalised.
+    x [K, r, bn]; pw [K, r, 1] client weight × coverage; c [K, r, 1]
+    coverage (rank mask × participation); t [r, 1] per-dimension trim
+    counts.  Client i's counting ranks ``lo``/``hi`` among the covering
+    clients compare it against every other client j, ties broken by client
+    index (so the trim set is deterministic under duplicates); a
+    contribution ranked inside either ``t[d]``-tail is dropped and the
+    survivors renormalised.  Each (i, j) pair is one 2-D [r, bn] compare —
+    j < i counts a tie as below, j > i as above.
     """
-    x = x_ref[...].astype(jnp.float32)              # [K, 1, r, bn]
-    p = p_ref[...].astype(jnp.float32)              # [K, 1]
-    cov = c_ref[...].astype(jnp.float32)            # [K, r]
-    t = t_ref[...].astype(jnp.float32)              # [1, r]
-    K = x.shape[0]
-    xi = x[:, None]                                 # [K, 1, 1, r, bn]
-    xj = x[None, :]                                 # [1, K, 1, r, bn]
-    ki = jax.lax.broadcasted_iota(jnp.int32, (K, K), 0)[:, :, None, None, None]
-    kj = jax.lax.broadcasted_iota(jnp.int32, (K, K), 1)[:, :, None, None, None]
-    cj = cov[None, :, None, :, None]                # [1, K, 1, r, 1]
-    lo = jnp.sum(cj * ((xj < xi) | ((xj == xi) & (kj < ki))), axis=1)
-    hi = jnp.sum(cj * ((xj > xi) | ((xj == xi) & (kj > ki))), axis=1)
-    tb = t[None, :, :, None]                        # [1, 1, r, 1]
-    keep = cov[:, None, :, None] * (lo >= tb) * (hi >= tb)   # [K, 1, r, bn]
-    pw = p[:, :, None, None]                        # [K, 1, 1, 1]
-    num = jnp.sum(keep * pw * x, axis=0)            # [1, r, bn]
-    den = jnp.sum(keep * pw, axis=0)
+    K = x_ref.shape[0]
+    xs = [x_ref[k].astype(jnp.float32) for k in range(K)]       # [r, bn]
+    cs = [c_ref[k].astype(jnp.float32) for k in range(K)]       # [r, 1]
+    t = t_ref[...].astype(jnp.float32)                          # [r, 1]
+    num = jnp.zeros_like(xs[0])
+    den = jnp.zeros_like(xs[0])
+    for i in range(K):
+        lo = jnp.zeros_like(xs[0])
+        hi = jnp.zeros_like(xs[0])
+        for j in range(K):
+            if j == i:
+                continue
+            below = xs[j] <= xs[i] if j < i else xs[j] < xs[i]
+            above = xs[j] > xs[i] if j < i else xs[j] >= xs[i]
+            lo = lo + jnp.where(below, cs[j], 0.0)
+            hi = hi + jnp.where(above, cs[j], 0.0)
+        w = jnp.where((lo >= t) & (hi >= t),
+                      pw_ref[i].astype(jnp.float32), 0.0)       # [r, bn]
+        num = num + w * xs[i]
+        den = den + w
     o_ref[...] = (num / jnp.maximum(den, 1e-12)).astype(o_ref.dtype)
 
 
@@ -89,26 +95,27 @@ def dim_agg_trimmed_pallas(stacked, p, cover, t, *, bn: int = 128,
                            interpret: bool = False):
     """stacked: [K, L, r, n]; p: [K] client weights; cover: [K, r] coverage
     mask; t: [r] per-dimension trim counts → [L, r, n].  Smaller default
-    block than ``dim_agg_pallas``: the kernel holds a [K, K, r, bn]
-    comparison in VMEM."""
+    block than ``dim_agg_pallas``: the kernel keeps every client's
+    [r, bn] tile live across the pairwise compare."""
     K, L, r, n = stacked.shape
     assert p.shape == (K,) and cover.shape == (K, r) and t.shape == (r,), (
         stacked.shape, p.shape, cover.shape, t.shape)
     bn = min(bn, n)
     assert n % bn == 0, (n, bn)
+    pw = (p[:, None] * cover)[..., None]                        # [K, r, 1]
     return pl.pallas_call(
         _kernel_trimmed,
         grid=(L, n // bn),
         in_specs=[
-            pl.BlockSpec((K, 1, r, bn), lambda l, j: (0, l, 0, j)),
-            pl.BlockSpec((K, 1), lambda l, j: (0, 0)),
-            pl.BlockSpec((K, r), lambda l, j: (0, 0)),
-            pl.BlockSpec((1, r), lambda l, j: (0, 0)),
+            pl.BlockSpec((K, pl.Squeezed(), r, bn), lambda l, j: (0, l, 0, j)),
+            pl.BlockSpec((K, r, 1), lambda l, j: (0, 0, 0)),
+            pl.BlockSpec((K, r, 1), lambda l, j: (0, 0, 0)),
+            pl.BlockSpec((r, 1), lambda l, j: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, r, bn), lambda l, j: (l, 0, j)),
+        out_specs=pl.BlockSpec((pl.Squeezed(), r, bn), lambda l, j: (l, 0, j)),
         out_shape=jax.ShapeDtypeStruct((L, r, n), stacked.dtype),
         interpret=interpret,
-    )(stacked, p.reshape(K, 1), cover, t.reshape(1, r))
+    )(stacked, pw, cover[..., None], t.reshape(r, 1))
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "interpret"))

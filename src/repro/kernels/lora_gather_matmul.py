@@ -17,6 +17,11 @@ TPU-native design (rides next to ``lora_matmul.py``'s single-adapter path):
 * grid (M, N/bn, K/bk) with one row per program: decode batches are
   one-token-per-slot, so M is the slot count and the row tile is [1, bk] —
   the adapter gather is per-row exact while W tiles stay MXU-aligned.
+  The row axis is carried as ``x[M, 1, K]`` / ``out[M, 1, N]`` with the M
+  dim squeezed out of the block: Mosaic needs the last two block dims to
+  be (8, 128)-divisible or whole, and a ``(1, bk)`` block of an ``[M, K]``
+  array is neither, while ``(1, bk)`` of ``[M, 1, K]`` is whole in its
+  second-minor dim;
   Chunked prefill reuses the same grid: the ``[B, chunk, d]`` block
   flattens to M = B·chunk rows whose idx entries repeat per slot
   (``ops.grouped_lora_matmul`` broadcasts a [B] index over the chunk
@@ -24,7 +29,11 @@ TPU-native design (rides next to ``lora_matmul.py``'s single-adapter path):
   pipelined BlockSpec DMA coalesces them;
 * K innermost: both accumulators (base [1, bn] and x@Aᵀ [1, r]) live in VMEM
   scratch across the K loop, one HBM pass over x and W, output written once;
-* accumulation is f32 scratch regardless of input dtype.
+* accumulation is f32 scratch regardless of input dtype; the low-rank path
+  contracts in f32 (the activation row is upcast, so a bf16 activation
+  meets an f32 adapter bank exactly as the jnp gather path's promotion
+  does), and both adapter contractions are transposed-RHS ``dot_general``s
+  (no in-kernel transpose).
 
 Heterogeneous-rank note: adapters of different ranks are zero-padded to the
 bank's shared r (rows of A / cols of B beyond the tenant's rank are zero),
@@ -42,6 +51,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+_NT = (((1,), (1,)), ((), ()))       # contract the last dims: x @ yᵀ
+
+
 def _kernel(idx_ref, x_ref, w_ref, a_ref, b_ref, o_ref, acc_ref, xa_ref, *,
             scale: float, k_steps: int):
     """One (row, bn) output tile; innermost grid dim accumulates over K.
@@ -57,13 +69,16 @@ def _kernel(idx_ref, x_ref, w_ref, a_ref, b_ref, o_ref, acc_ref, xa_ref, *,
 
     x = x_ref[...]                                         # [1, bk]
     acc_ref[...] += jnp.dot(x, w_ref[...], preferred_element_type=jnp.float32)
-    # xa: [1, r] accumulated over the K loop — A tile is [1, r, bk]
-    xa_ref[...] += jnp.dot(x, a_ref[0].T, preferred_element_type=jnp.float32)
+    # xa: [1, r] accumulated over the K loop — the A tile is [r, bk]
+    xa_ref[...] += jax.lax.dot_general(
+        x.astype(jnp.float32), a_ref[...].astype(jnp.float32), _NT,
+        preferred_element_type=jnp.float32)
 
     @pl.when(kk == k_steps - 1)
     def _flush():
-        delta = jnp.dot(xa_ref[...], b_ref[0].T,
-                        preferred_element_type=jnp.float32)
+        delta = jax.lax.dot_general(                       # [1, bn]
+            xa_ref[...], b_ref[...].astype(jnp.float32), _NT,
+            preferred_element_type=jnp.float32)
         o_ref[...] = (acc_ref[...] + scale * delta).astype(o_ref.dtype)
 
 
@@ -85,25 +100,27 @@ def grouped_lora_matmul_pallas(x, w, a, b, idx, *, scale: float = 1.0,
     bn, bk = min(bn, N), min(bk, K)
     assert N % bn == 0 and K % bk == 0, (N, K, bn, bk)
     k_steps = K // bk
+    row = pl.Squeezed()
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(M, N // bn, k_steps),
         in_specs=[
-            pl.BlockSpec((1, bk), lambda i, j, k, idx: (i, k)),       # x row
-            pl.BlockSpec((bk, bn), lambda i, j, k, idx: (k, j)),      # w
-            pl.BlockSpec((1, r, bk), lambda i, j, k, idx: (idx[i], 0, k)),
-            pl.BlockSpec((1, bn, r), lambda i, j, k, idx: (idx[i], j, 0)),
+            pl.BlockSpec((row, 1, bk), lambda i, j, k, idx: (i, 0, k)),  # x
+            pl.BlockSpec((bk, bn), lambda i, j, k, idx: (k, j)),         # w
+            pl.BlockSpec((row, r, bk), lambda i, j, k, idx: (idx[i], 0, k)),
+            pl.BlockSpec((row, bn, r), lambda i, j, k, idx: (idx[i], j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bn), lambda i, j, k, idx: (i, j)),
+        out_specs=pl.BlockSpec((row, 1, bn), lambda i, j, k, idx: (i, 0, j)),
         scratch_shapes=[
             pltpu.VMEM((1, bn), jnp.float32),              # base accumulator
             pltpu.VMEM((1, r), jnp.float32),               # x@Aᵀ accumulator
         ],
     )
-    return pl.pallas_call(
+    y = pl.pallas_call(
         functools.partial(_kernel, scale=scale, k_steps=k_steps),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((M, 1, N), x.dtype),
         interpret=interpret,
-    )(idx.astype(jnp.int32), x, w, a, b)
+    )(idx.astype(jnp.int32), x.reshape(M, 1, K), w, a, b)
+    return y.reshape(M, N)

@@ -1,9 +1,12 @@
 """jit'd dispatch wrappers for the Pallas kernels.
 
-On this CPU container the kernels execute in interpret mode (the Pallas body
-runs in Python for correctness validation); on TPU the same call sites lower
-to Mosaic.  ``interpret=None`` auto-detects.  Inputs that don't tile exactly
-are zero-padded to the block grid and the result is sliced back.
+The mode follows the backend, never a silent default: on a TPU backend the
+kernels always lower to Mosaic (asking for interpret mode there raises); on
+the CPU backend — the one the tests force — ``interpret=None`` runs the
+Pallas body in interpret mode for correctness checks, and an explicit
+``interpret=False`` lowers for a described TPU topology (the compile tests);
+any other backend raises.  Inputs that don't tile exactly are zero-padded
+to the block grid and the result is sliced back.
 """
 
 from __future__ import annotations
@@ -18,8 +21,20 @@ from repro.kernels.lora_gather_matmul import grouped_lora_matmul_pallas
 from repro.kernels.lora_matmul import lora_matmul_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret(interpret: bool | None) -> bool:
+    """Resolve a kernel call's interpret flag from the backend (see the
+    module docstring)."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        if interpret:
+            raise ValueError("Pallas interpret mode requested on a TPU "
+                             "backend; the kernels lower to Mosaic there")
+        return False
+    if backend != "cpu":
+        raise RuntimeError(
+            f"no Pallas kernel path for backend {backend!r}: the kernels "
+            "lower to Mosaic on a TPU and interpret on the CPU backend only")
+    return True if interpret is None else interpret
 
 
 def _pad_to(x, axis: int, mult: int):
@@ -34,8 +49,7 @@ def _pad_to(x, axis: int, mult: int):
 def fused_lora_matmul(x, w, a, b, *, scale: float = 1.0, bm: int = 256,
                       bn: int = 256, bk: int = 512, interpret: bool | None = None):
     """y = x@W + scale·(x@Aᵀ)@Bᵀ with arbitrary leading batch dims on x."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _interpret(interpret)
     lead = x.shape[:-1]
     K = x.shape[-1]
     N = w.shape[1]
@@ -58,8 +72,7 @@ def grouped_lora_matmul(x, w, a, b, idx, *, scale: float = 1.0, bn: int = 256,
     b: [G, N, r]; idx: i32 broadcastable to x's leading dims — a per-batch
     [B] index against x [B, chunk, K] (the chunked-prefill shape) is
     broadcast over the chunk axis."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _interpret(interpret)
     lead = x.shape[:-1]
     K = x.shape[-1]
     N = w.shape[1]
@@ -83,8 +96,7 @@ def dimension_wise_aggregate(stacked, weights, scale=None, *, bn: int = 512,
     """FediLoRA Eq. 5 over one stacked leaf [K, L, r, n] with w̃ [K, r];
     ``scale`` [K] optionally multiplies each client's weight row in-kernel
     (the FedBuff staleness discount)."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _interpret(interpret)
     n = stacked.shape[-1]
     bn_ = min(bn, n)
     sp = _pad_to(stacked, 3, bn_)
@@ -178,8 +190,7 @@ def dimension_wise_trimmed(stacked, p, cover, t, *, bn: int = 128,
     (see ``dim_agg_trimmed_pallas``); pads the feature axis to the block
     grid with zeros (padding is sliced off before it can influence real
     elements — each element trims independently)."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _interpret(interpret)
     n = stacked.shape[-1]
     bn_ = min(bn, n)
     sp = _pad_to(stacked, 3, bn_)
@@ -215,8 +226,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     """q: [B,Sq,H,d]; k,v: [B,Sk,KV,d] (GQA) → [B,Sq,H,dv].  Folds heads
     into the batch grid dim, repeats KV heads for GQA, pads Sq/Sk to the
     tile grid and slices back."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _interpret(interpret)
     B, Sq, H, d = q.shape
     Sk, KV = k.shape[1], k.shape[2]
     dv = v.shape[-1]

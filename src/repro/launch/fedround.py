@@ -260,11 +260,10 @@ def _make_client_phases(cfg: ModelConfig, opt_cfg: OptimizerConfig, *,
     from repro.sharding import round_mesh_axes
     ax, model_ax = round_mesh_axes(mesh)        # raises on malformed meshes
     if model_ax is None:
-        from jax.experimental.shard_map import shard_map
-        return shard_map(
-            _client_phases, mesh,
+        return jax.shard_map(
+            _client_phases, mesh=mesh,
             in_specs=(P(), P(), P(ax), P(ax), P(ax)),
-            out_specs=(P(ax), P(ax), P(ax)), check_rep=False)
+            out_specs=(P(ax), P(ax), P(ax)), check_vma=False)
 
     row = NamedSharding(mesh, P(ax))
 
@@ -431,6 +430,19 @@ def make_round_engine(cfg: ModelConfig, opt_cfg: OptimizerConfig, *,
         hetlora_prune_gamma=hetlora_prune_gamma, mesh=mesh,
         n_sample=n_pad)
 
+    def aggregate(agg_lora, ranks_s, p, agg_kw):
+        return AG.aggregate(aggregator, agg_lora, ranks_s, p,
+                            hetlora_beta=hetlora_beta, lora_scale=lora_scale,
+                            clip=clip, trim=trim, **agg_kw)
+
+    if mesh is not None and aggregator.endswith("_kernel"):
+        # the *_kernel registry entries reduce in a Mosaic kernel, which
+        # GSPMD cannot partition: gather the cohort's updates and run the
+        # whole (tiny) aggregation on every device
+        from jax.sharding import PartitionSpec as P
+        aggregate = jax.shard_map(aggregate, mesh=mesh, in_specs=P(),
+                                  out_specs=P(), check_vma=False)
+
     def round_step(base_params, stacked_lora, global_lora, prev_global,
                    ranks, sizes, data, idx, cids, batch_idx, round_idx,
                    fault=None):
@@ -512,10 +524,7 @@ def make_round_engine(cfg: ModelConfig, opt_cfg: OptimizerConfig, *,
             }
 
         # --- aggregation through the shared registry -----------------------
-        global_new, base_delta = AG.aggregate(
-            aggregator, agg_lora, ranks_s, p,
-            hetlora_beta=hetlora_beta, lora_scale=lora_scale,
-            clip=clip, trim=trim, **agg_kw)
+        global_new, base_delta = aggregate(agg_lora, ranks_s, p, agg_kw)
 
         out = {
             # scatter the sampled clients back into the persistent stack

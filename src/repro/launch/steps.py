@@ -136,7 +136,8 @@ def make_multi_adapter_serve_step(cfg: ModelConfig, *, lora_scale: float,
     * ``"grouped"`` — the Pallas BGMV kernel
       (``kernels/lora_gather_matmul.py``): the per-row index is a
       scalar-prefetch operand steering the A/B BlockSpec DMA, so the
-      gather happens in the memory system (interpret mode off-TPU).
+      gather happens in the memory system (interpret mode on the CPU
+      backend only).
 
     Both are mathematically identical to running each row through
     ``make_serve_step`` with its own adapter (tested).  ``bank_layout``:

@@ -2,8 +2,9 @@
 
 Runs the paper's full training loop — heterogeneous-rank clients, missing
 modalities, dimension-wise aggregation + layer-wise editing — on any
-registered architecture at a CPU-tractable reduced scale, or at bench scale
-on the paper-proxy models.
+registered architecture, at the config's own dtype and widths (``--reduced``
+picks the smoke-scale variant).  ``--aggregator`` takes every entry of
+``repro.core.aggregation.AGGREGATORS``, the Pallas kernel paths included.
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train --arch fedbench-tiny \
@@ -17,22 +18,19 @@ from __future__ import annotations
 import argparse
 import json
 
-import numpy as np
-
 from repro.configs import get_config, get_reduced_config
+from repro.core.aggregation import AGGREGATORS
 from repro.core.editing import EditConfig
 from repro.data.missing import apply_missing_modality
 from repro.data.partition import heterogeneous_sizes
 from repro.data.synthetic import SyntheticTaskConfig, make_federated_datasets
 from repro.federated import FederatedConfig, FederatedTrainer
+from repro.launch.compile_cache import use_compile_cache
 from repro.optim import OptimizerConfig
 
 
 def build_trainer(args) -> FederatedTrainer:
     mcfg = get_reduced_config(args.arch) if args.reduced else get_config(args.arch)
-    if mcfg.dtype != "float32":
-        import dataclasses
-        mcfg = dataclasses.replace(mcfg, dtype="float32")  # CPU training
     tcfg = SyntheticTaskConfig(vocab_size=min(mcfg.vocab_size, 256),
                                image_dim=mcfg.vision_dim or 32, seed=args.seed)
     sizes = heterogeneous_sizes(args.clients, args.examples, seed=args.seed)
@@ -79,7 +77,7 @@ def main():
     ap.add_argument("--batch-size", type=int, default=8)
     ap.add_argument("--examples", type=int, default=800)
     ap.add_argument("--aggregator", default="fedilora",
-                    choices=["fedavg", "hetlora", "flora", "fedilora"])
+                    choices=sorted(AGGREGATORS))
     ap.add_argument("--missing-ratio", type=float, default=0.0)
     ap.add_argument("--dirichlet-alpha", type=float, default=0.5)
     ap.add_argument("--no-edit", action="store_true")
@@ -96,6 +94,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    use_compile_cache()
     trainer = build_trainer(args)
     for r in range(args.rounds):
         rec = trainer.run_round()

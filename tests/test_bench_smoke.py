@@ -242,3 +242,65 @@ def test_bench_history_appends(tmp_path, monkeypatch):
     assert len(doc2["history"]) == 3
     assert doc2["history"][-1]["results"]["speedup"] == 1.9
     assert doc2["history"][-1]["timestamp"] is not None
+
+
+def test_run_exits_nonzero_when_a_suite_fails(monkeypatch, capsys):
+    """A failing suite is recorded as an ERROR row, the other suites still
+    run, and the harness's exit code says something failed."""
+    import benchmarks.run as R
+
+    def boom():
+        raise ValueError("boom")
+
+    monkeypatch.setattr(R, "SUITES", {"bad": boom,
+                                      "good": lambda: ["good/x,1.0,ok"]})
+    monkeypatch.setattr(sys, "argv", ["run", "bad", "good"])
+    import repro.launch.compile_cache as CC
+    monkeypatch.setattr(CC, "use_compile_cache", lambda: None)
+    assert R.main() == 1
+    out = capsys.readouterr().out
+    assert "bad/ERROR,0.0,ValueError: boom" in out
+    assert "good/x,1.0,ok" in out
+    monkeypatch.setattr(sys, "argv", ["run", "good"])
+    assert R.main() == 0
+
+
+@pytest.mark.parametrize("backend", ["tpu", "gpu"])
+def test_measure_runs_in_process_on_tpu_only(monkeypatch, backend):
+    """On a TPU the measurement runs in the process that holds the chip
+    (no child process); a backend that is neither TPU nor the CPU test
+    backend is an error, not a default."""
+    import jax
+
+    import benchmarks.common as C
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(C, "run_measurement_subprocess",
+                        lambda *a, **k: pytest.fail("spawned a child"))
+    if backend == "tpu":
+        assert C.measure(lambda: {"ran": "here"}, "TAG:") == {"ran": "here"}
+    else:
+        with pytest.raises(RuntimeError, match="gpu"):
+            C.measure(lambda: {}, "TAG:")
+
+
+def test_compile_cache_placed_from_outside(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing else is set; without it
+    the cache goes to the checkout's fixed .jax_cache/.  Nothing compiles
+    between setting and restoring the option, so nothing is written."""
+    import jax
+
+    import repro.launch.compile_cache as CC
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert CC.use_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = CC.use_compile_cache()
+        assert path == os.path.join(CC.CHECKOUT, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert os.path.isfile(os.path.join(CC.CHECKOUT, "chip_smoke.py"))

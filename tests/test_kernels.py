@@ -8,8 +8,10 @@ import numpy as np
 import pytest
 from hypothesis import given, settings
 
-from repro.kernels.ops import dimension_wise_aggregate, fused_lora_matmul
-from repro.kernels.ref import dim_agg_ref, lora_matmul_ref
+from repro.kernels import ops
+from repro.kernels.ops import (dimension_wise_aggregate,
+                               dimension_wise_trimmed, fused_lora_matmul)
+from repro.kernels.ref import dim_agg_ref, dim_agg_trimmed_ref, lora_matmul_ref
 
 SHAPES = [
     (64, 128, 128, 4), (128, 256, 192, 8), (256, 512, 384, 16),
@@ -86,3 +88,60 @@ def test_dim_agg_dtypes(dtype):
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=tol)
+
+
+@pytest.mark.parametrize("K", [2, 5, 8])
+@pytest.mark.parametrize("trim", [0.2, 0.4])
+def test_dim_agg_trimmed_allclose(K, trim):
+    """The pairwise-unrolled trimmed kernel against the [K, K, ...] oracle,
+    with duplicated clients (index tie-break) and partial coverage."""
+    from repro.core.aggregation import trimmed_dimension_counts
+
+    rng = np.random.default_rng(K)
+    x = np.round(rng.normal(size=(K, 2, 16, 200)), 1).astype(np.float32)
+    x[1] = x[0]
+    p = rng.uniform(0.1, 1.0, K).astype(np.float32)
+    cover = (rng.uniform(size=(K, 16)) > 0.3).astype(np.float32)
+    t = trimmed_dimension_counts(jnp.asarray(cover), trim)
+    out = dimension_wise_trimmed(jnp.asarray(x), jnp.asarray(p / p.sum()),
+                                 jnp.asarray(cover), t, interpret=True)
+    ref = dim_agg_trimmed_ref(jnp.asarray(x), jnp.asarray(p / p.sum()),
+                              jnp.asarray(cover), t)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
+
+
+def test_trimmed_kernel_aggregator_matches_reference():
+    from repro.core import aggregation as AG
+    from repro.core.lora import LoRAConfig, LoRASpec, init_lora_params
+
+    specs = [LoRASpec("s0.attn.wq", 24, 32, 2)]
+    key = jax.random.PRNGKey(3)
+    loras = [init_lora_params(jax.random.fold_in(key, i), specs,
+                              LoRAConfig(rank=16), client_rank=r)
+             for i, r in enumerate((4, 8, 8, 16, 16))]
+    stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *loras)
+    ranks = jnp.asarray([4, 8, 8, 16, 16])
+    p = jnp.asarray([0.1, 0.2, 0.2, 0.25, 0.25])
+    ref, _ = AG.aggregate("fedilora_trimmed", stacked, ranks, p, trim=0.3)
+    ker, _ = AG.aggregate("fedilora_trimmed_kernel", stacked, ranks, p,
+                          trim=0.3)
+    for n in ref:
+        for m in ("A", "B"):
+            np.testing.assert_allclose(np.asarray(ker[n][m]),
+                                       np.asarray(ref[n][m]), atol=1e-6)
+
+
+@pytest.mark.parametrize("backend,requested,expect", [
+    ("tpu", None, False), ("tpu", False, False), ("tpu", True, ValueError),
+    ("cpu", None, True), ("cpu", False, False), ("cpu", True, True),
+    ("gpu", None, RuntimeError)])
+def test_interpret_mode_follows_backend(monkeypatch, backend, requested,
+                                        expect):
+    """A TPU backend never interprets; interpret is the CPU backend's
+    default; any other backend has no kernel path."""
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: backend)
+    if isinstance(expect, bool):
+        assert ops._interpret(requested) is expect
+    else:
+        with pytest.raises(expect):
+            ops._interpret(requested)

@@ -68,12 +68,17 @@ def test_round_2x2_matches_single_device_all_aggregators():
     aggregator family (fedavg / hetlora+prune / fedilora / the Pallas
     dim_agg kernel entry / flora) must reproduce the single-device engine
     (allclose — TP reassociates float sums), stay ONE jitted round_step
-    dispatch per round, and the 2-D population eval must match the
-    per-client loop exactly."""
+    dispatch per round and one compile, and the 2-D population eval must
+    match the per-client loop exactly."""
     code = _MK + """
     mesh = Mesh(np.array(jax.devices()).reshape(2, 2), ("client", "model"))
     cases = [("fedavg", {}), ("hetlora", {"hetlora_prune_gamma": 0.9}),
              ("fedilora", {}), ("fedilora_kernel", {}), ("flora", {})]
+    import collections
+    compiles = collections.Counter()
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda name, secs, fun_name=None, **_: compiles.update(
+            [fun_name] if name.endswith("backend_compile_duration") else []))
     for agg, kw in cases:
         tm = mk(agg, mesh=mesh, **kw)
         ts = mk(agg, **kw)
@@ -89,6 +94,9 @@ def test_round_2x2_matches_single_device_all_aggregators():
         # ONE fused dispatch per round, nothing else
         assert tm.dispatch_count["round_step"] == 2
         assert set(tm.dispatch_count) == {"round_step"}, tm.dispatch_count
+        # ... and ONE compile each (tm, ts): round 1 already sees the
+        # placed shardings
+        assert compiles.pop("jit(round_step)") == 2, compiles
         print("agg OK", agg)
     # population eval over the 2-D mesh == per-client loop (exact decode)
     tm = mk("fedilora", mesh=mesh)
@@ -414,6 +422,19 @@ def test_make_round_mesh_rejects_missing_devices():
         make_round_mesh(too_many)
     with pytest.raises(ValueError, match="needs"):
         make_round_mesh(too_many, 2)
+
+
+def test_launch_meshes_have_auto_axes():
+    """``jax.make_mesh`` defaults to Explicit axes, which refuse the round
+    engine's ``with_sharding_constraint`` pins; the launch helpers build
+    Auto meshes (the 2x2 round failed to trace on a chip mesh otherwise)."""
+    from jax.sharding import AxisType
+
+    from repro.launch.mesh import _auto_mesh, make_debug_mesh
+
+    for mesh in (_auto_mesh((1, 1), ("client", "model")),
+                 make_debug_mesh(1, 1)):
+        assert set(mesh.axis_types) == {AxisType.Auto}
 
 
 def test_serving_params_never_fsdp_over_the_slot_axis():
