@@ -106,12 +106,15 @@ def lora_delta(entry: Mapping[str, jax.Array], scale: float) -> jax.Array:
     return scale * jnp.einsum("lor,lri->loi", entry["B"], entry["A"])
 
 
+@jax.named_scope("lora_site")
 def lora_matmul(x: jax.Array, w: jax.Array, lora: Mapping[str, jax.Array] | None,
                 scale: float) -> jax.Array:
     """``y = x @ w + scale * (x @ A^T) @ B^T`` — the LoRA-adapted projection.
 
     ``x``: [..., in_dim]; ``w``: [in_dim, out_dim]; ``A``: [r, in]; ``B``: [out, r].
-    Padded rank rows/cols are zero so they contribute nothing.
+    Padded rank rows/cols are zero so they contribute nothing.  This and
+    :func:`grouped_lora_matmul` run under the ``lora_site`` named scope, so
+    a profile finds every LoRA-carrying projection whatever implements it.
     """
     y = x @ w
     if lora is not None:
@@ -121,6 +124,7 @@ def lora_matmul(x: jax.Array, w: jax.Array, lora: Mapping[str, jax.Array] | None
     return y
 
 
+@jax.named_scope("lora_site")
 def grouped_lora_matmul(x: jax.Array, w: jax.Array,
                         bank: Mapping[str, jax.Array] | None, idx: jax.Array,
                         scale: float, *, kernel: bool = False) -> jax.Array:

@@ -115,6 +115,7 @@ def dim_agg_trimmed_pallas(stacked, p, cover, t, *, bn: int = 128,
         out_specs=pl.BlockSpec((pl.Squeezed(), r, bn), lambda l, j: (l, 0, j)),
         out_shape=jax.ShapeDtypeStruct((L, r, n), stacked.dtype),
         interpret=interpret,
+        name="dim_agg_pallas",
     )(stacked, pw, cover[..., None], t.reshape(r, 1))
 
 
@@ -147,4 +148,5 @@ def dim_agg_pallas(stacked, weights, scale=None, *, bn: int = 512,
         out_specs=pl.BlockSpec((1, r, bn), lambda l, j: (l, 0, j)),
         out_shape=jax.ShapeDtypeStruct((L, r, n), stacked.dtype),
         interpret=interpret,
+        name="dim_agg_pallas",
     )(*operands)

@@ -104,4 +104,5 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq, dv), jnp.float32),   # output accumulator
         ],
         interpret=interpret,
+        name="flash_attention_pallas",
     )(q, k, v)

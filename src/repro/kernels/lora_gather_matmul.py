@@ -122,5 +122,6 @@ def grouped_lora_matmul_pallas(x, w, a, b, idx, *, scale: float = 1.0,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, 1, N), x.dtype),
         interpret=interpret,
+        name="grouped_lora_matmul_pallas",
     )(idx.astype(jnp.int32), x.reshape(M, 1, K), w, a, b)
     return y.reshape(M, N)

@@ -82,4 +82,5 @@ def lora_matmul_pallas(x, w, a, b, *, scale: float = 1.0, bm: int = 256,
             pltpu.VMEM((bm, r), jnp.float32),                  # x@Aᵀ accumulator
         ],
         interpret=interpret,
+        name="lora_matmul_pallas",
     )(x, w, a, b)

@@ -417,6 +417,12 @@ def make_round_engine(cfg: ModelConfig, opt_cfg: OptimizerConfig, *,
     With ``faults=False`` (the default) the engine signature and program
     are exactly the pre-fault ones — the zero-fault timeline is trivially
     bit-identical.
+
+    The round's phases run under named scopes (HLO metadata only):
+    ``fedround.gather`` (batch gather and redistribution), the client
+    phases' ``fedround.local_train`` / ``prune`` / ``edit``,
+    ``fedround.aggregate`` (fault absorption and the registry's
+    aggregation) and ``fedround.scatter`` (into the persistent stack).
     """
     edit = edit or EditConfig()
     lcfg = LoRAConfig(rank=r_g)
@@ -460,80 +466,88 @@ def make_round_engine(cfg: ModelConfig, opt_cfg: OptimizerConfig, *,
         if not faults:
             p = sizes_s / jnp.maximum(jnp.sum(sizes_s), 1e-12)
 
-        # --- device-side batch gather: [n_s, steps, B, ...] ----------------
-        batches = {k: v[gidx[:, None, None], batch_idx]
-                   for k, v in data.items()}
+        with jax.named_scope("fedround.gather"):
+            # --- device-side batch gather: [n_s, steps, B, ...] ------------
+            batches = {k: v[gidx[:, None, None], batch_idx]
+                       for k, v in data.items()}
 
-        # --- server → client redistribution (on device) --------------------
-        if aggregator == "flora":
-            # FLoRA: server folded last round's delta into base; clients
-            # restart from a fresh per-(round, client) init (Wang et al.)
-            def _init(k):
-                return init_lora_params(
-                    jax.random.PRNGKey(1000 * round_idx + k), specs, lcfg)
+            # --- server → client redistribution (on device) ----------------
+            if aggregator == "flora":
+                # FLoRA: server folded last round's delta into base; clients
+                # restart from a fresh per-(round, client) init (Wang et al.)
+                def _init(k):
+                    return init_lora_params(
+                        jax.random.PRNGKey(1000 * round_idx + k), specs, lcfg)
 
-            lora0 = jax.vmap(lambda k, r: mask_lora_params(_init(k), r, r_g))(
-                cids, ranks_s)
-        else:
-            lora0 = jax.vmap(
-                lambda r: truncate_redistribute(global_lora, r, r_g))(ranks_s)
+                lora0 = jax.vmap(
+                    lambda k, r: mask_lora_params(_init(k), r, r_g))(
+                        cids, ranks_s)
+            else:
+                lora0 = jax.vmap(lambda r: truncate_redistribute(
+                    global_lora, r, r_g))(ranks_s)
 
         # --- per-client phases, parallel over the client axis --------------
         lora1, ranks_s, metrics = client_phases(
             base_params, prev_global, lora0, ranks_s, batches)
 
-        # --- fault absorption (wire corruption + health guards) -------------
-        agg_lora = lora1
-        scatter_idx = idx
-        health = None
-        agg_kw = {}
-        if aggregator in ("fedilora_clip", "fedilora_clip_kernel"):
-            agg_kw["anchor"] = global_lora   # clipped-away mass stays here
-        if faults:
-            f = _pad_fault(fault, idx.shape[0])
-            # corruption hits the wire copy only — the client's stored
-            # adapter (scattered below) stays clean
-            agg_lora = jax.tree_util.tree_map(
-                lambda x: x * _broadcast_rows(f["scale"], x).astype(x.dtype)
-                + _broadcast_rows(f["nan"], x).astype(x.dtype), lora1)
-            finite = _rows_finite(agg_lora)
-            agg_lora = _sanitize_rows(agg_lora, finite)
-            sizes_agg = (sizes_s * f["weight"]
-                         * finite.astype(sizes_s.dtype))
-            p = sizes_agg / jnp.maximum(jnp.sum(sizes_agg), 1e-12)
-            # dropped clients never write back: their scatter index goes out
-            # of range, mode="drop" discards it (the dummy-client idiom)
-            scatter_idx = jnp.where(f["keep"] > 0, idx, ranks.shape[0])
-            agg_kw["fallback"] = global_lora
-            vf = valid.astype(jnp.float32)
-            alive = vf * (f["keep"] > 0) * (f["weight"] > 0)
-            if AG._clip_active(clip):
-                norms = AG.client_update_norms(agg_lora)
-                part = alive * finite.astype(jnp.float32)
-                clip_rate = (jnp.sum(part * (norms > clip))
-                             / jnp.maximum(jnp.sum(part), 1.0))
-            else:
-                clip_rate = jnp.float32(0.0)
-            health = {
-                "n_dropped": jnp.sum(vf * (f["keep"] <= 0)),
-                "n_forfeited": jnp.sum(vf * (f["keep"] > 0)
-                                       * (f["weight"] <= 0)),
-                "n_nonfinite": jnp.sum(alive * (1.0 - finite.astype(
-                    jnp.float32))),
-                "clip_rate": clip_rate,
-            }
+        with jax.named_scope("fedround.aggregate"):
+            # --- fault absorption (wire corruption + health guards) ---------
+            agg_lora = lora1
+            scatter_idx = idx
+            health = None
+            agg_kw = {}
+            if aggregator in ("fedilora_clip", "fedilora_clip_kernel"):
+                agg_kw["anchor"] = global_lora   # clipped-away mass stays here
+            if faults:
+                f = _pad_fault(fault, idx.shape[0])
+                # corruption hits the wire copy only — the client's stored
+                # adapter (scattered below) stays clean
+                agg_lora = jax.tree_util.tree_map(
+                    lambda x: (x * _broadcast_rows(f["scale"], x).astype(x.dtype)
+                               + _broadcast_rows(f["nan"], x).astype(x.dtype)),
+                    lora1)
+                finite = _rows_finite(agg_lora)
+                agg_lora = _sanitize_rows(agg_lora, finite)
+                sizes_agg = (sizes_s * f["weight"]
+                             * finite.astype(sizes_s.dtype))
+                p = sizes_agg / jnp.maximum(jnp.sum(sizes_agg), 1e-12)
+                # dropped clients never write back: their scatter index goes
+                # out of range, mode="drop" discards it (the dummy-client
+                # idiom)
+                scatter_idx = jnp.where(f["keep"] > 0, idx, ranks.shape[0])
+                agg_kw["fallback"] = global_lora
+                vf = valid.astype(jnp.float32)
+                alive = vf * (f["keep"] > 0) * (f["weight"] > 0)
+                if AG._clip_active(clip):
+                    norms = AG.client_update_norms(agg_lora)
+                    part = alive * finite.astype(jnp.float32)
+                    clip_rate = (jnp.sum(part * (norms > clip))
+                                 / jnp.maximum(jnp.sum(part), 1.0))
+                else:
+                    clip_rate = jnp.float32(0.0)
+                health = {
+                    "n_dropped": jnp.sum(vf * (f["keep"] <= 0)),
+                    "n_forfeited": jnp.sum(vf * (f["keep"] > 0)
+                                           * (f["weight"] <= 0)),
+                    "n_nonfinite": jnp.sum(alive * (1.0 - finite.astype(
+                        jnp.float32))),
+                    "clip_rate": clip_rate,
+                }
 
-        # --- aggregation through the shared registry -----------------------
-        global_new, base_delta = aggregate(agg_lora, ranks_s, p, agg_kw)
+            # --- aggregation through the shared registry -------------------
+            global_new, base_delta = aggregate(agg_lora, ranks_s, p, agg_kw)
 
-        out = {
+        with jax.named_scope("fedround.scatter"):
             # scatter the sampled clients back into the persistent stack
             # (mode="drop" — the jax default — discards dummy rows, whose
             # index is out of bounds by construction)
-            "stacked_lora": jax.tree_util.tree_map(
+            stacked_new = jax.tree_util.tree_map(
                 lambda s, u: s.at[scatter_idx].set(u, mode="drop"),
-                stacked_lora, lora1),
-            "ranks": ranks.at[scatter_idx].set(ranks_s, mode="drop"),
+                stacked_lora, lora1)
+            ranks_new = ranks.at[scatter_idx].set(ranks_s, mode="drop")
+        out = {
+            "stacked_lora": stacked_new,
+            "ranks": ranks_new,
             # the input global becomes prev_global: an explicit pass-through
             # output, so donation of the input buffer stays safe
             "prev_global": global_lora,

@@ -127,6 +127,7 @@ def _attn_mask(q_pos, k_pos, causal: bool, window: int):
     return jnp.where(ok, 0.0, NEG_INF).astype(jnp.float32)
 
 
+@jax.named_scope("attention")
 def multihead_attention(q, k, v, *, causal: bool, window: int = 0, softcap: float = 0.0,
                         q_pos=None, k_pos=None, pad_mask=None, chunked: bool | None = None,
                         q_chunk: int = 512, kv_chunk: int = 1024):
@@ -625,6 +626,7 @@ def init_mlp(key, d: int, ff: int, dtype, n: int = 1):
     }
 
 
+@jax.named_scope("mlp")
 def mlp_forward(params, x):
     h = jax.nn.silu(x @ params["w1"]) * (x @ params["w3"])
     return h @ params["w2"]

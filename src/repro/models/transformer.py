@@ -275,13 +275,12 @@ def encode(cfg: ModelConfig, params, audio, lora=None, lora_scale: float = 1.0,
     return L.rms_norm(x, enc["final_ln"], cfg.norm_eps)
 
 
-def forward(cfg: ModelConfig, params, tokens, *, lora=None, lora_scale: float = 1.0,
-            vision=None, audio=None, pad_mask=None, audio_mask=None,
-            remat: bool = False, last_only: bool = False, act_spec=None,
-            moe_spec=None):
-    """Training / prefill forward.  Returns (logits, aux_loss); logits are
-    [B,S,V], or [B,1,V] when ``last_only`` (prefill — avoids the full-seq
-    unembed matmul)."""
+def _final_hidden(cfg: ModelConfig, params, tokens, *, lora=None,
+                  lora_scale: float = 1.0, vision=None, audio=None,
+                  pad_mask=None, audio_mask=None, remat: bool = False,
+                  last_only: bool = False, act_spec=None, moe_spec=None):
+    """:func:`forward` up to the unembedding: (final-norm hidden states
+    [B,S,d] or [B,1,d], aux_loss)."""
     x = params["embed"][tokens]
     B, S = tokens.shape
     positions = jnp.arange(S)
@@ -311,31 +310,44 @@ def forward(cfg: ModelConfig, params, tokens, *, lora=None, lora_scale: float = 
         x = x[:, n_prefix:]
     if last_only:
         x = x[:, -1:]
+    return x, aux
+
+
+def _unembed(cfg: ModelConfig, params, x):
     if cfg.tie_embeddings:
-        logits = x @ params["embed"].T
-    else:
-        logits = x @ params["unembed"]
-    return logits, aux
+        return x @ params["embed"].T
+    return x @ params["unembed"]
+
+
+def forward(cfg: ModelConfig, params, tokens, **kw):
+    """Training / prefill forward.  Returns (logits, aux_loss); logits are
+    [B,S,V], or [B,1,V] when ``last_only`` (prefill — avoids the full-seq
+    unembed matmul).  Keywords as :func:`_final_hidden`."""
+    x, aux = _final_hidden(cfg, params, tokens, **kw)
+    return _unembed(cfg, params, x), aux
 
 
 def loss_fn(cfg: ModelConfig, params, lora, batch, lora_scale: float = 1.0,
             remat: bool = False, act_spec=None, moe_spec=None):
     """Masked next-token cross-entropy (+ MoE aux).  batch keys: tokens,
-    labels, loss_mask, optional image/audio + modality masks."""
+    labels, loss_mask, optional image/audio + modality masks.  The
+    unembedding, the f32 log-softmax and the NLL run under the
+    ``unembed_loss`` named scope (HLO metadata, forward and backward)."""
     vision = batch.get("image")
     if vision is not None and "image_mask" in batch:
         vision = (vision * batch["image_mask"][:, None, None]).astype(vision.dtype)
-    logits, aux = forward(cfg, params, batch["tokens"], lora=lora,
-                          lora_scale=lora_scale, vision=vision,
-                          audio=batch.get("audio"), remat=remat,
-                          act_spec=act_spec, moe_spec=moe_spec)
-    logits = logits.astype(jnp.float32)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ll = jnp.take_along_axis(logp, batch["labels"][..., None], axis=-1)[..., 0]
-    mask = batch["loss_mask"].astype(jnp.float32)
-    loss = -jnp.sum(ll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
-    acc = jnp.sum((jnp.argmax(logits, -1) == batch["labels"]) * mask) / jnp.maximum(
-        jnp.sum(mask), 1.0)
+    x, aux = _final_hidden(cfg, params, batch["tokens"], lora=lora,
+                           lora_scale=lora_scale, vision=vision,
+                           audio=batch.get("audio"), remat=remat,
+                           act_spec=act_spec, moe_spec=moe_spec)
+    with jax.named_scope("unembed_loss"):
+        logits = _unembed(cfg, params, x).astype(jnp.float32)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        ll = jnp.take_along_axis(logp, batch["labels"][..., None], axis=-1)[..., 0]
+        mask = batch["loss_mask"].astype(jnp.float32)
+        loss = -jnp.sum(ll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+        acc = jnp.sum((jnp.argmax(logits, -1) == batch["labels"]) * mask) / jnp.maximum(
+            jnp.sum(mask), 1.0)
     return loss + aux, {"loss": loss, "aux": aux, "acc": acc}
 
 
@@ -463,11 +475,7 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, pos, *, lora=None,
 
     x, new_cache = lax.scan(body, x, (params["blocks"], lora_scan, cache))
     x = L.rms_norm(x, params["final_ln"], cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = x[:, 0] @ params["embed"].T
-    else:
-        logits = x[:, 0] @ params["unembed"]
-    return logits.astype(jnp.float32), new_cache
+    return _unembed(cfg, params, x[:, 0]).astype(jnp.float32), new_cache
 
 
 def decode_chunk(cfg: ModelConfig, params, cache, embeds, pos, *,
@@ -556,8 +564,4 @@ def decode_chunk(cfg: ModelConfig, params, cache, embeds, pos, *,
     if not logits:
         return None, new_cache
     x = L.rms_norm(x, params["final_ln"], cfg.norm_eps)
-    if cfg.tie_embeddings:
-        out = x[:, 0] @ params["embed"].T
-    else:
-        out = x[:, 0] @ params["unembed"]
-    return out.astype(jnp.float32), new_cache
+    return _unembed(cfg, params, x[:, 0]).astype(jnp.float32), new_cache

@@ -50,7 +50,10 @@ only when a request completes.  ``dispatch_count`` tallies ``serve_step``
 (exactly one per decode step — asserted by tests), ``serve_prefill``
 (exactly ``max_s ⌈P_s/chunk⌉`` per admission burst, recorded in
 ``prefill_bursts`` and asserted), ``serve_admit``, ``adapter_load`` and
-``fetch``.  Completion records carry
+``fetch``.  The counters ``serving.prefill_tokens`` (prompt positions
+filled) and ``serving.prefill_rows`` (rows the prefill program computed,
+``max_slots × prefill_chunk`` per dispatch) give the prefill row use,
+their ratio.  Completion records carry
 ``latency_s`` and ``ttft_s`` (submit → the step() call that emitted the
 request's first token; dispatch-clock, not device-sync — the scheduling
 delay chunked prefill attacks).
@@ -361,6 +364,11 @@ class ServingEngine:
         self._h_queue_wait = m.histogram("serving.queue_wait_seconds")
         self._c_tokens = m.counter("serving.generated_tokens")
         self._c_completed = m.counter("serving.completed_requests")
+        # chunked prefill's work: prompt positions filled, and the rows the
+        # prefill program computed for them (every dispatch computes all
+        # max_slots × prefill_chunk rows); their ratio is the row use
+        self._c_prefill_tokens = m.counter("serving.prefill_tokens")
+        self._c_prefill_rows = m.counter("serving.prefill_rows")
         # overload/fault accounting: these are the ONLY places rejected /
         # shed / timed-out / faulted requests show up — they never touch
         # the TTFT/latency/queue-wait histograms above
@@ -595,6 +603,9 @@ class ServingEngine:
             n_disp = max(-(-f // self.prefill_chunk) for f in fills)
             self.prefill_bursts.append(
                 {"fills": fills, "dispatches": n_disp})
+            self._c_prefill_tokens.inc(sum(fills))
+            self._c_prefill_rows.inc(
+                n_disp * self.max_slots * self.prefill_chunk)
             with self.telemetry.span("prefill_burst", cat="serving",
                                      slots=len(newly), dispatches=n_disp):
                 for _ in range(n_disp):

@@ -8,6 +8,8 @@ these tests run the TPU compiler against a *described* v5e:2x2 topology
 fixture, never at import: only one process may hold the TPU library.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -87,3 +89,43 @@ def test_flash_attention_compiles(one_chip, seq):
         fn, one_chip, ((1, seq, HEADS, HEAD_DIM), jnp.bfloat16),
         ((1, seq, KV_HEADS, HEAD_DIM), jnp.bfloat16),
         ((1, seq, KV_HEADS, HEAD_DIM), jnp.bfloat16))
+
+
+
+_NAMED = [   # (id, the kernel's name, wrapper, operand shapes)
+    ("dim_agg", "dim_agg_pallas",
+     lambda s, w: ops.dimension_wise_aggregate(s, w, interpret=False),
+     [((COHORT, LAYERS, RANK, D_MODEL), jnp.float32),
+      ((COHORT, RANK), jnp.float32)]),
+    ("dim_agg_trimmed", "dim_agg_pallas",
+     lambda s, p, c, t: ops.dimension_wise_trimmed(s, p, c, t,
+                                                   interpret=False),
+     [((COHORT, LAYERS, RANK, D_MODEL), jnp.float32),
+      ((COHORT,), jnp.float32), ((COHORT, RANK), jnp.float32),
+      ((RANK,), jnp.float32)]),
+    ("grouped_lora_matmul", "grouped_lora_matmul_pallas",
+     lambda x, w, a, b, i: ops.grouped_lora_matmul(x, w, a, b, i,
+                                                   interpret=False),
+     [((8, D_MODEL), jnp.bfloat16), ((D_MODEL, D_MODEL), jnp.bfloat16),
+      ((BANK, RANK, D_MODEL), jnp.float32),
+      ((BANK, D_MODEL, RANK), jnp.float32), ((8,), jnp.int32)]),
+    ("lora_matmul", "lora_matmul_pallas",
+     lambda x, w, a, b: ops.fused_lora_matmul(x, w, a, b, interpret=False),
+     [((256, D_MODEL), jnp.bfloat16), ((D_MODEL, D_MODEL), jnp.bfloat16),
+      ((RANK, D_MODEL), jnp.bfloat16), ((D_MODEL, RANK), jnp.bfloat16)]),
+    ("flash_attention", "flash_attention_pallas",
+     lambda q, k, v: ops.flash_attention(q, k, v, interpret=False),
+     [((1, 256, HEADS, HEAD_DIM), jnp.bfloat16),
+      ((1, 256, KV_HEADS, HEAD_DIM), jnp.bfloat16),
+      ((1, 256, KV_HEADS, HEAD_DIM), jnp.bfloat16)]),
+]
+
+
+@pytest.mark.parametrize("name,fn,shapes", [k[1:] for k in _NAMED],
+                         ids=[k[0] for k in _NAMED])
+def test_kernel_compiled_name(one_chip, name, fn, shapes):
+    """Each kernel's Mosaic call carries the name its ``pallas_call`` gives
+    it, the prefix by which a profile's reduction finds the kernel."""
+    text = _hlo(fn, one_chip, *shapes)
+    assert re.search(rf"%{name}(\.\d+)? = \S+ custom-call\(.*"
+                     r'custom_call_target="tpu_custom_call"', text)
