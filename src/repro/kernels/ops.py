@@ -17,7 +17,8 @@ import jax.numpy as jnp
 from repro.kernels import ref
 from repro.kernels.dim_agg import dim_agg_pallas, dim_agg_trimmed_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.lora_gather_matmul import grouped_lora_matmul_pallas
+from repro.kernels.lora_gather_matmul import (block_sizes,
+                                              grouped_lora_matmul_pallas)
 from repro.kernels.lora_matmul import lora_matmul_pallas
 
 
@@ -65,30 +66,33 @@ def fused_lora_matmul(x, w, a, b, *, scale: float = 1.0, bm: int = 256,
     return y[:M, :N].reshape(*lead, N)
 
 
-def grouped_lora_matmul(x, w, a, b, idx, *, scale: float = 1.0, bn: int = 256,
-                        bk: int = 512, interpret: bool | None = None):
+def grouped_lora_matmul(x, w, a, b, idx, *, scale: float = 1.0,
+                        bn: int | None = None, bk: int | None = None,
+                        interpret: bool | None = None):
     """Multi-tenant LoRA projection: row ``m`` uses adapter ``idx[m]`` from
     the stacked bank (BGMV).  x: [..., K]; w: [K, N]; a: [G, r, K];
-    b: [G, N, r]; idx: i32 broadcastable to x's leading dims — a per-batch
-    [B] index against x [B, chunk, K] (the chunked-prefill shape) is
-    broadcast over the chunk axis."""
+    b: [G, N, r]; idx: i32 over x's leading dims, broadcast over the rest —
+    a per-batch [B] index against x [B, chunk, K] (the chunked-prefill
+    shape) covers each slot's chunk, and those chunk rows form the kernel's
+    row block.  ``bn``/``bk`` force N/K tiles; by default K and N are whole
+    where they fit VMEM (``lora_gather_matmul.block_sizes``)."""
     interpret = _interpret(interpret)
     lead = x.shape[:-1]
     K = x.shape[-1]
     N = w.shape[1]
-    x2 = x.reshape(-1, K)
     idx = jnp.asarray(idx)
-    if idx.ndim and idx.ndim < len(lead):
-        idx = idx.reshape(idx.shape + (1,) * (len(lead) - idx.ndim))
-    idx2 = jnp.broadcast_to(idx, lead).reshape(-1)
-    bn_, bk_ = min(bn, N), min(bk, K)
-    xp = _pad_to(x2, 1, bk_)
+    idx_b = jnp.broadcast_to(idx, lead[:idx.ndim]).reshape(-1)
+    x3 = x.reshape(idx_b.shape[0], -1, K)
+    R = x3.shape[1]
+    br, bn_, bk_ = block_sizes(R, K, N, a.shape[1], x.dtype, w.dtype,
+                               a.dtype, bn, bk)
+    xp = _pad_to(_pad_to(x3, 1, br), 2, bk_)
     wp = _pad_to(_pad_to(w, 0, bk_), 1, bn_)
     ap = _pad_to(a, 2, bk_)
     bp = _pad_to(b, 1, bn_)
-    y = grouped_lora_matmul_pallas(xp, wp, ap, bp, idx2, scale=scale, bn=bn_,
-                                   bk=bk_, interpret=interpret)
-    return y[:, :N].reshape(*lead, N)
+    y = grouped_lora_matmul_pallas(xp, wp, ap, bp, idx_b, scale=scale,
+                                   br=br, bn=bn_, bk=bk_, interpret=interpret)
+    return y[:, :R, :N].reshape(*lead, N)
 
 
 def dimension_wise_aggregate(stacked, weights, scale=None, *, bn: int = 512,

@@ -69,11 +69,14 @@ def test_dim_agg_trimmed_compiles(one_chip, cohort):
 
 
 @pytest.mark.parametrize("n", [HEADS * HEAD_DIM, KV_HEADS * HEAD_DIM])
-@pytest.mark.parametrize("rows", [(8,), (8, 8)], ids=["decode", "prefill"])
+@pytest.mark.parametrize("rows", [(8,), (8, 8), (32, 1), (32, 128)],
+                         ids=["decode", "prefill", "engine-decode",
+                              "engine-prefill"])
 def test_grouped_lora_matmul_compiles(one_chip, rows, n):
-    """BGMV at the serving engine's shapes: decode M = slots, chunked
-    prefill [slots, chunk] (M = slots·chunk); bf16 activations and base
-    weights, the adapter bank in its own f32."""
+    """BGMV at the serving engine's shapes: decode [slots] or [slots, 1]
+    (one row per block), chunked prefill [slots, chunk] (one chunk-row
+    block per slot; 32 slots × chunk 128 is the benchmark's engine);
+    bf16 activations and base weights, the adapter bank in its own f32."""
     fn = lambda x, w, a, b, i: ops.grouped_lora_matmul(
         x, w, a, b, i, scale=0.5, interpret=False)
     assert "tpu_custom_call" in _hlo(
