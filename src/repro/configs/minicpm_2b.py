@@ -1,5 +1,9 @@
 """minicpm-2b [dense] — 40L d_model=2304 36H (GQA kv=36) d_ff=5760
-vocab=122753 — llama-like; trained with the WSD schedule. [arXiv:2404.06395]
+vocab=122753 — llama-like with µP scalings; trained with the WSD schedule.
+[arXiv:2404.06395; huggingface.co/openbmb/MiniCPM-2B-sft-bf16]
+
+µP (``modeling_minicpm.py``): embeddings × 12, each residual branch ×
+1.4/√40, the final hidden state ÷ (2304/256) before the tied unembedding.
 
 The WSD (warmup-stable-decay) schedule is implemented in
 ``repro.optim.schedules.wsd_schedule`` and selected by this arch's training
@@ -19,8 +23,12 @@ CONFIG = ModelConfig(
     d_ff=5760,
     vocab_size=122753,
     tie_embeddings=True,
+    norm_eps=1e-5,
+    scale_emb=12.0,
+    scale_depth=1.4,
+    dim_model_base=256,
     dtype="bfloat16",
-    source="arXiv:2404.06395 (MiniCPM)",
+    source="arXiv:2404.06395 (MiniCPM); huggingface.co/openbmb/MiniCPM-2B-sft-bf16",
 )
 
 REDUCED = ModelConfig(
@@ -34,6 +42,10 @@ REDUCED = ModelConfig(
     d_ff=512,
     vocab_size=512,
     tie_embeddings=True,
+    norm_eps=1e-5,
+    scale_emb=12.0,
+    scale_depth=1.4,
+    dim_model_base=256,
     dtype="float32",
     source="reduced smoke variant",
 )

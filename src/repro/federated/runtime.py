@@ -594,7 +594,10 @@ class FederatedTrainer:
           row axis over the client axis (replicated when K doesn't divide);
         * frozen base params: ``sharding.param_spec`` — tensor-parallel
           over ``"model"`` on a 2-D mesh, degrading to replication on a
-          1-D client mesh (no ``model``/``data`` axes to shard over);
+          1-D client mesh (no ``model``/``data`` axes to shard over); a
+          vocabulary the model axis does not divide is padded
+          (``sharding.pad_vocab``) so that the embedding and the loss
+          split over it too;
         * global/prev adapters, ranks, sizes: replicated (aggregation
           objects).
 
@@ -607,7 +610,7 @@ class FederatedTrainer:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from repro import sharding as SH
-        client_ax, _ = SH.round_mesh_axes(m)
+        client_ax, model_ax = SH.round_mesh_axes(m)
         row = P(client_ax) if (self.fcfg.num_clients
                                % m.shape[client_ax] == 0) else P()
         rows = NamedSharding(m, row)
@@ -621,6 +624,9 @@ class FederatedTrainer:
         # TP-only placement: the round mesh's first axis is the CLIENT
         # axis whatever its name — FSDP'ing the frozen base over it would
         # all-gather the weights per use
+        if model_ax is not None:
+            self.base_params = SH.pad_vocab(self.base_params,
+                                            m.shape[model_ax])
         self.base_params = jax.device_put(
             self.base_params,
             SH.tree_param_shardings(self.base_params, m,

@@ -191,7 +191,7 @@ def make_chunked_prefill_step(cfg: ModelConfig, *, lora_scale: float,
         Sp = state["ptoks"].shape[1]
         tok_pos = jnp.clip(offs - n_prefix, 0, Sp - 1)
         toks = jnp.take_along_axis(state["ptoks"], tok_pos, axis=1)
-        embeds = params["embed"][toks]                           # [B, C, d]
+        embeds = T.embed_tokens(cfg, params, toks)              # [B, C, d]
         if n_prefix:
             rows = jnp.arange(B)[:, None]
             pre = state["vis"][rows, jnp.clip(offs, 0, n_prefix - 1)]
@@ -231,7 +231,7 @@ def make_greedy_generate(cfg: ModelConfig, *, lora_scale: float,
 
     def generate(params, lora, tokens, vision=None):
         B = tokens.shape[0]
-        xs = params["embed"][tokens[:, : cap_start + 1]]        # [B, P_txt, d]
+        xs = T.embed_tokens(cfg, params, tokens[:, : cap_start + 1])  # [B, P, d]
         n_prefix = 0
         if vision is not None and cfg.family == "vlm" and cfg.vision_mode == "prefix":
             pre = vision.astype(xs.dtype) @ params["vision_proj"]

@@ -79,6 +79,15 @@ class ModelConfig:
     sliding_window: int = 0              # for "attn_local" layers
     attn_logit_softcap: float = 0.0
 
+    # --- µP scalings (MiniCPM, arXiv:2404.06395 §4) --------------------------
+    # token embeddings × scale_emb; each attention / MLP branch ×
+    # scale_depth / √num_layers before its residual add; the final hidden
+    # state ÷ (d_model / dim_model_base) before the unembedding.  The
+    # defaults leave the stack unscaled (None: no such scaling).
+    scale_emb: float = 1.0
+    scale_depth: float | None = None
+    dim_model_base: int | None = None
+
     # --- mixtures ----------------------------------------------------------
     moe: MoEConfig | None = None
     mla: MLAConfig | None = None
@@ -103,6 +112,21 @@ class ModelConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or (self.d_model // self.num_heads)
+
+    @property
+    def residual_scale(self) -> float:
+        """Multiplier of every residual branch (µP ``scale_depth``)."""
+        if self.scale_depth is None:
+            return 1.0
+        return self.scale_depth / self.num_layers ** 0.5
+
+    @property
+    def logit_scale(self) -> float:
+        """Multiplier of the final hidden state before the unembedding
+        (µP ``dim_model_base / d_model``)."""
+        if self.dim_model_base is None:
+            return 1.0
+        return self.dim_model_base / self.d_model
 
     @property
     def num_blocks(self) -> int:

@@ -155,6 +155,26 @@ def _split_key(name: str) -> tuple[str, str]:
 
 
 # ---------------------------------------------------------------------------
+# µP scalings — one helper each, called by every path (training, prefill,
+# decode, the serving engine) so that they cannot drift apart
+# ---------------------------------------------------------------------------
+
+def embed_tokens(cfg: ModelConfig, params, tokens):
+    """Token embeddings, × ``cfg.scale_emb``."""
+    x = params["embed"][tokens]
+    if cfg.scale_emb != 1.0:
+        x = x * cfg.scale_emb
+    return x
+
+
+def add_residual(cfg: ModelConfig, x, y):
+    """``x + y``, the branch ``y`` × ``cfg.residual_scale``."""
+    if cfg.residual_scale != 1.0:
+        y = y * cfg.residual_scale
+    return x + y
+
+
+# ---------------------------------------------------------------------------
 # forward (training / prefill)
 # ---------------------------------------------------------------------------
 
@@ -190,7 +210,7 @@ def _apply_sublayer(cfg: ModelConfig, kind: str, bp, x, *, lora_tree, sub_idx,
         y = L.mamba_forward(mp, h, cfg)
     else:
         raise ValueError(kind)
-    x = x + y
+    x = add_residual(cfg, x, y)
 
     if cfg.family == "encdec" and "dec_cross" in bp:
         hx = L.rms_norm(x, bp["lnx"], cfg.norm_eps)
@@ -198,15 +218,15 @@ def _apply_sublayer(cfg: ModelConfig, kind: str, bp, x, *, lora_tree, sub_idx,
         y = L.attention_forward(bp["dec_cross"], hx, cfg, kind="cross_attn",
                                 lora=lo, lora_scale=lora_scale, kv_src=enc_out,
                                 pad_mask=enc_mask)
-        x = x + y
+        x = add_residual(cfg, x, y)
 
     if "moe" in bp:
         h2 = L.rms_norm(x, bp["ln2"], cfg.norm_eps)
         y, aux = L.moe_forward(bp["moe"], h2, cfg, expert_spec=moe_spec)
-        x = x + y
+        x = add_residual(cfg, x, y)
     elif "ffn" in bp:
         h2 = L.rms_norm(x, bp["ln2"], cfg.norm_eps)
-        x = x + L.mlp_forward(bp["ffn"], h2)
+        x = add_residual(cfg, x, L.mlp_forward(bp["ffn"], h2))
     return x, aux
 
 
@@ -281,7 +301,7 @@ def _final_hidden(cfg: ModelConfig, params, tokens, *, lora=None,
                   last_only: bool = False, act_spec=None, moe_spec=None):
     """:func:`forward` up to the unembedding: (final-norm hidden states
     [B,S,d] or [B,1,d], aux_loss)."""
-    x = params["embed"][tokens]
+    x = embed_tokens(cfg, params, tokens)
     B, S = tokens.shape
     positions = jnp.arange(S)
 
@@ -314,9 +334,19 @@ def _final_hidden(cfg: ModelConfig, params, tokens, *, lora=None,
 
 
 def _unembed(cfg: ModelConfig, params, x):
-    if cfg.tie_embeddings:
-        return x @ params["embed"].T
-    return x @ params["unembed"]
+    """Logits of final hidden states, the state × ``cfg.logit_scale``.  A
+    vocabulary padded past ``cfg.vocab_size`` (``sharding.pad_vocab``)
+    gives its pad rows the lowest finite logit: they never win an argmax
+    and take no probability mass or gradient."""
+    if cfg.logit_scale != 1.0:
+        x = x * cfg.logit_scale
+    w = params["embed"].T if cfg.tie_embeddings else params["unembed"]
+    logits = x @ w
+    V = logits.shape[-1]
+    if V > cfg.vocab_size:
+        logits = jnp.where(jnp.arange(V) < cfg.vocab_size, logits,
+                           jnp.finfo(logits.dtype).min)
+    return logits
 
 
 def forward(cfg: ModelConfig, params, tokens, **kw):
@@ -418,7 +448,8 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, pos, *, lora=None,
     embedding — used to stream non-token positions (e.g. the VLM vision
     prefix) through the KV cache during cached prefill."""
     lora = lora or {}
-    x = embeds if embeds is not None else params["embed"][tokens][:, None, :]
+    x = (embeds if embeds is not None
+         else embed_tokens(cfg, params, tokens)[:, None, :])
     lora_scan = {k: v for k, v in lora.items() if k.startswith("s")}
 
     def body(carry, xs):
@@ -453,7 +484,7 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, pos, *, lora=None,
                         mp[w] = mp[w] + lora_scale * jnp.einsum(
                             "or,ri->io", lo[w]["B"], lo[w]["A"]).astype(mp[w].dtype)
                 y, new_ci[pre] = L.mamba_decode(mp, hn, ci[pre], cfg)
-            h = h + y
+            h = add_residual(cfg, h, y)
             if cfg.family == "encdec":
                 hx = L.rms_norm(h, bp[pre]["lnx"], cfg.norm_eps)
                 lo = _sub_lora(lt, f"{pre}.dec_cross")
@@ -462,15 +493,15 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, pos, *, lora=None,
                                           kind="cross_attn", pos=pos,
                                           lora=lo, lora_scale=lora_scale)
                 new_ci[f"{pre}_dec_cross"] = ci[f"{pre}_dec_cross"]
-                h = h + y
+                h = add_residual(cfg, h, y)
             if "moe" in bp[pre]:
                 h2 = L.rms_norm(h, bp[pre]["ln2"], cfg.norm_eps)
                 y, _ = L.moe_forward(bp[pre]["moe"], h2, cfg,
                                      expert_spec=moe_spec)
-                h = h + y
+                h = add_residual(cfg, h, y)
             elif "ffn" in bp[pre]:
                 h2 = L.rms_norm(h, bp[pre]["ln2"], cfg.norm_eps)
-                h = h + L.mlp_forward(bp[pre]["ffn"], h2)
+                h = add_residual(cfg, h, L.mlp_forward(bp[pre]["ffn"], h2))
         return h, new_ci
 
     x, new_cache = lax.scan(body, x, (params["blocks"], lora_scan, cache))
@@ -549,15 +580,15 @@ def decode_chunk(cfg: ModelConfig, params, cache, embeds, pos, *,
             else:
                 raise NotImplementedError(
                     f"batched decode does not support {kind!r}")
-            h = h + y
+            h = add_residual(cfg, h, y)
             if "moe" in bp[pre]:
                 h2 = L.rms_norm(h, bp[pre]["ln2"], cfg.norm_eps)
                 y, _ = L.moe_forward(bp[pre]["moe"], h2, cfg,
                                      expert_spec=moe_spec)
-                h = h + y
+                h = add_residual(cfg, h, y)
             elif "ffn" in bp[pre]:
                 h2 = L.rms_norm(h, bp[pre]["ln2"], cfg.norm_eps)
-                h = h + L.mlp_forward(bp[pre]["ffn"], h2)
+                h = add_residual(cfg, h, L.mlp_forward(bp[pre]["ffn"], h2))
         return h, new_ci
 
     x, new_cache = lax.scan(body, embeds, (params["blocks"], lora_scan, cache))

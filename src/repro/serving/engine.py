@@ -406,7 +406,7 @@ class ServingEngine:
             prompt_tok = jnp.take_along_axis(state["ptoks"], tok_pos[:, None],
                                              axis=1)[:, 0]
             tok = jnp.where(pos < plen, prompt_tok, last)
-            embeds = params["embed"][tok]                       # [B, d]
+            embeds = T.embed_tokens(cfg, params, tok)          # [B, d]
             if n_prefix:
                 rows = jnp.arange(pos.shape[0])
                 pre = state["vis"][rows, jnp.clip(pos, 0, n_prefix - 1)]

@@ -20,6 +20,7 @@ from __future__ import annotations
 from typing import Any
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -138,6 +139,19 @@ def lora_spec(path: tuple, shape: tuple, mesh: Mesh, mode: str = "baseline") -> 
     """LoRA adapters replicate — they are the cross-client aggregation
     objects and tiny relative to base weights."""
     return P()
+
+
+def pad_vocab(params: Pytree, multiple: int) -> Pytree:
+    """``params`` with the embedding's rows zero-padded to a multiple of
+    ``multiple``, so that :func:`param_spec` can split the vocabulary over
+    a ``"model"`` axis of that size where the published count does not
+    divide (MiniCPM's 122,753).  A tied ``transformer._unembed`` gives the
+    pad its lowest logit, so the pad changes no loss, gradient or argmax;
+    the lookup never reads it (token ids stay below ``vocab_size``)."""
+    pad = -params["embed"].shape[0] % multiple
+    if not pad:
+        return params
+    return dict(params, embed=jnp.pad(params["embed"], ((0, pad), (0, 0))))
 
 
 def _path_names(path) -> tuple:

@@ -116,6 +116,14 @@ def test_full_config_matches_assignment(arch):
         assert cfg.moe.num_shared_experts == 2 and cfg.moe.d_ff_expert == 1536
     if arch == "llama4-scout-17b-a16e":
         assert cfg.moe.num_experts == 16 and cfg.moe.experts_per_token == 1
+    # µP scalings: MiniCPM's published scale_emb / scale_depth /
+    # dim_model_base and RMSNorm ε; every other stack unscaled
+    mup = {"minicpm-2b": (12.0, 1.4, 256)}.get(arch, (1.0, None, None))
+    assert (cfg.scale_emb, cfg.scale_depth, cfg.dim_model_base) == mup
+    if arch == "minicpm-2b":
+        assert cfg.norm_eps == 1e-5
+        assert cfg.residual_scale == pytest.approx(1.4 / 40 ** 0.5)
+        assert cfg.logit_scale == pytest.approx(1 / 9)
 
 
 def test_moe_aux_loss_and_capacity():

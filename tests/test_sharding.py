@@ -303,3 +303,52 @@ def test_cohort_pad():
     assert cohort_pad(3, ROUND_2D) == 4          # client axis 4
     assert cohort_pad(5, CLIENT_1D) == 8
     assert cohort_pad(1, FakeMesh((("c", 2), ("model", 1)))) == 2
+
+
+@pytest.mark.parametrize("multiple", [2, 4])
+def test_pad_vocab_changes_no_loss_or_gradient(multiple):
+    """A vocabulary padded to a multiple of the model axis (MiniCPM's odd
+    122,753, here 509) gives the same masked loss, accuracy and LoRA
+    gradients as the unpadded one: the pad rows' logits sit at the lowest
+    finite value, so they take no probability mass and never win the
+    argmax.  The same float32 ops on the same values, the unembedding's
+    contraction over V blocked differently: equal to rounding (1e-5 of the
+    largest gradient)."""
+    import jax.numpy as jnp
+
+    from repro.configs.minicpm_2b import REDUCED
+    from repro.core.lora import LoRAConfig, init_lora_params
+    from repro.models import transformer as T
+
+    cfg = dataclasses.replace(REDUCED, vocab_size=509)
+    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    padded = SH.pad_vocab(params, multiple)
+    assert padded["embed"].shape[0] == -(-509 // multiple) * multiple > 509
+    np.testing.assert_array_equal(padded["embed"][:509], params["embed"])
+    lora = init_lora_params(jax.random.PRNGKey(1), T.lora_specs(cfg),
+                            LoRAConfig(rank=4))
+    lora = {n: dict(e, B=0.05 * jax.random.normal(jax.random.PRNGKey(2),
+                                                  e["B"].shape))
+            for n, e in lora.items()}           # B ≠ 0: A gets gradients
+    rng = np.random.default_rng(0)
+    toks = jnp.asarray(rng.integers(0, 509, (2, 12)), jnp.int32)
+    batch = {"tokens": toks, "labels": jnp.roll(toks, -1, 1),
+             "loss_mask": jnp.ones((2, 12), jnp.float32)}
+
+    def run(p):
+        return jax.value_and_grad(
+            lambda lo: T.loss_fn(cfg, p, lo, batch, 2.0), has_aux=True)(lora)
+
+    (l0, m0), g0 = run(params)
+    (l1, m1), g1 = run(padded)
+    np.testing.assert_allclose(l1, l0, rtol=1e-6)
+    assert float(m1["acc"]) == float(m0["acc"])
+    for name in g0:           # a longer contraction over V re-blocks sums
+        for m in ("A", "B"):
+            want = np.asarray(g0[name][m])
+            np.testing.assert_allclose(g1[name][m], want, rtol=1e-5,
+                                       atol=1e-5 * np.abs(want).max())
+    logits, _ = T.forward(cfg, padded, toks)
+    assert logits.shape[-1] == padded["embed"].shape[0]
+    assert bool(jnp.all(jnp.argmax(logits, -1) < 509))
+    assert bool(jnp.all(jnp.isfinite(logits)))
